@@ -57,19 +57,20 @@ def arch_step_rows(archs=ARCH_NAMES) -> list[str]:
 
 def kernel_rows() -> list[str]:
     rows = ["table,kernel,variant,us_per_call,derived"]
+    backend = jax.devices()[0].platform  # interpret on cpu, compiled on tpu
     key = jax.random.key(0)
     n = 1 << 14
     s = jax.random.uniform(key, (n,), jnp.float32, 5, 30)
     x = jax.random.uniform(key, (n,), jnp.float32, 1, 100)
     t = jax.random.uniform(key, (n,), jnp.float32, 0.5, 5)
-    rows.append(f"kernel,black_scholes,pallas_interpret,"
+    rows.append(f"kernel,black_scholes,pallas_{backend},"
                 f"{_time(lambda: black_scholes(s, x, t)):.0f},n={n}")
     rows.append(f"kernel,black_scholes,jnp_ref,"
                 f"{_time(lambda: jax.jit(lambda: black_scholes_ref(s, x, t, 0.02, 0.3))()):.0f},n={n}")
 
     a = jax.random.normal(key, (256, 512), jnp.float32)
     b = jax.random.normal(key, (512, 256), jnp.float32)
-    rows.append(f"kernel,streamed_matmul,pallas_interpret,"
+    rows.append(f"kernel,streamed_matmul,pallas_{backend},"
                 f"{_time(lambda: matmul(a, b)):.0f},256x512x256")
     rows.append(f"kernel,streamed_matmul,jnp_ref,"
                 f"{_time(lambda: jax.jit(lambda: matmul_ref(a, b))()):.0f},256x512x256")
@@ -77,11 +78,11 @@ def kernel_rows() -> list[str]:
     q = jax.random.normal(key, (1, 256, 4, 64), jnp.float32)
     kk = jax.random.normal(key, (1, 256, 2, 64), jnp.float32)
     v = jax.random.normal(key, (1, 256, 2, 64), jnp.float32)
-    rows.append(f"kernel,flash_attention,pallas_interpret,"
+    rows.append(f"kernel,flash_attention,pallas_{backend},"
                 f"{_time(lambda: flash_attention(q, kk, v, block_q=128, block_kv=128)):.0f},S=256")
 
     g = jax.random.normal(key, (16, 24, 136), jnp.float32)
     c = jnp.array([0.5, 0.1, 0.05, 0.02, 0.01], jnp.float32)
-    rows.append(f"kernel,fdtd3d,pallas_interpret,"
+    rows.append(f"kernel,fdtd3d,pallas_{backend},"
                 f"{_time(lambda: fdtd3d_step(g, c)):.0f},16x24x136")
     return rows

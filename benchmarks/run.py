@@ -19,7 +19,8 @@ Emits, as CSV blocks:
                 per platform x regime x strategy kind (§16) [not --fast]
   table1        working-set sizing
   lm            per-arch reduced train/decode step timings (real CPU)
-  kernel        Pallas-kernel call timings (interpret mode) vs jnp oracle
+  kernel        Pallas-kernel call timings vs jnp oracle; the variant names
+                the backend (pallas_cpu runs in interpret mode)
   roofline      §Roofline terms per (arch x shape) from dry-run artifacts
   dryrun        §Dry-run compile/memory summary, both meshes
 

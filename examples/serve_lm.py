@@ -6,4 +6,4 @@
 from repro.launch.serve import serve
 
 for arch in ("qwen2-7b", "mixtral-8x22b", "rwkv6-3b", "musicgen-medium"):
-    serve(arch, batch=2, prompt_len=32, gen=12)
+    serve(arch, reduced=True, batch=2, prompt_len=32, gen=12)

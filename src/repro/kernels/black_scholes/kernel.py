@@ -32,7 +32,7 @@ def bs_kernel(s_ref, x_ref, t_ref, call_ref, put_ref, *, r: float, v: float):
 
 
 def black_scholes_pallas(s, x, t, r: float, v: float, *,
-                         block_rows: int = 256, interpret: bool = True):
+                         block_rows: int = 256, interpret: bool):
     """s/x/t: 2-D (rows, LANE-multiple cols) arrays, same shape/dtype."""
     rows, cols = s.shape
     assert cols % LANE == 0, f"cols must be multiple of {LANE}"

@@ -6,12 +6,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import use_interpret
 from repro.kernels.black_scholes.kernel import LANE, black_scholes_pallas
 from repro.kernels.black_scholes.ref import black_scholes_ref
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("r", "v", "use_pallas"))
@@ -35,6 +32,6 @@ def black_scholes(s, x, t, *, r: float = 0.02, v: float = 0.30,
 
     call, put = black_scholes_pallas(
         prep(s), prep(x), prep(t), r, v, block_rows=block,
-        interpret=_use_interpret(),
+        interpret=use_interpret(),
     )
     return call.reshape(-1)[:n].reshape(shape), put.reshape(-1)[:n].reshape(shape)

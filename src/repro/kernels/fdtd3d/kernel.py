@@ -41,7 +41,7 @@ def _fdtd_kernel(cur_ref, nxt_ref, c_ref, o_ref, *, Y: int, X: int):
     o_ref[...] = out.astype(o_ref.dtype)
 
 
-def fdtd3d_pallas(padded, coeffs, *, interpret: bool = True):
+def fdtd3d_pallas(padded, coeffs, *, interpret: bool):
     """padded: (Z+2R, Y+2R, X+2R) with Z % BZ == 0; coeffs: (RADIUS+1,)."""
     R = RADIUS
     Zp, Yp, Xp = padded.shape

@@ -6,12 +6,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import use_interpret
 from repro.kernels.fdtd3d.kernel import BZ, fdtd3d_pallas
 from repro.kernels.fdtd3d.ref import RADIUS, fdtd3d_ref
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad(grid):
@@ -25,7 +22,7 @@ def fdtd3d_step(grid, coeffs, *, use_pallas: bool = True):
     padded = _pad(grid)
     if not use_pallas:
         return fdtd3d_ref(padded, coeffs)
-    return fdtd3d_pallas(padded, coeffs, interpret=_use_interpret())
+    return fdtd3d_pallas(padded, coeffs, interpret=use_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("steps", "use_pallas"))

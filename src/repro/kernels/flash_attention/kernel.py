@@ -84,7 +84,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True, window=None,
                            block_q: int = 512, block_kv: int = 512,
-                           interpret: bool = True):
+                           interpret: bool):
     """q: (B,Sq,Hq,Dh); k/v: (B,Skv,Hkv,Dh) -> (B,Sq,Hq,Dh)."""
     b, sq, hq, dh = q.shape
     _, skv, hkv, _ = k.shape

@@ -5,12 +5,9 @@ import functools
 
 import jax
 
+from repro.kernels.backend import use_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import flash_attention_ref
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(
@@ -23,5 +20,5 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
         return flash_attention_ref(q, k, v, causal=causal, window=window)
     return flash_attention_pallas(
         q, k, v, causal=causal, window=window,
-        block_q=block_q, block_kv=block_kv, interpret=_use_interpret(),
+        block_q=block_q, block_kv=block_kv, interpret=use_interpret(),
     )

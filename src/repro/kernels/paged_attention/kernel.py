@@ -74,7 +74,7 @@ def _pa_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_attention_pallas(q, kv_pool_k, kv_pool_v, block_table, seq_lens,
-                           *, interpret: bool = True):
+                           *, interpret: bool):
     b, hq, dh = q.shape
     npages, psz, hkv, _ = kv_pool_k.shape
     pages_per_seq = block_table.shape[1]

@@ -5,12 +5,9 @@ import functools
 
 import jax
 
+from repro.kernels.backend import use_interpret
 from repro.kernels.paged_attention.kernel import paged_attention_pallas
 from repro.kernels.paged_attention.ref import paged_attention_ref
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
@@ -26,5 +23,5 @@ def paged_attention(q, kv_pool_k, kv_pool_v, block_table, seq_lens,
         return paged_attention_ref(q, kv_pool_k, kv_pool_v, block_table, seq_lens)
     return paged_attention_pallas(
         q, kv_pool_k, kv_pool_v, block_table, seq_lens,
-        interpret=_use_interpret(),
+        interpret=use_interpret(),
     )

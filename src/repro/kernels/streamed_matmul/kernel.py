@@ -31,7 +31,7 @@ def mm_kernel(a_ref, b_ref, o_ref, acc_ref):
 
 
 def matmul_pallas(a, b, *, bm: int = 256, bk: int = 512, bn: int = 256,
-                  out_dtype=None, interpret: bool = True):
+                  out_dtype=None, interpret: bool):
     """a: (M,K), b: (K,N); M/K/N multiples of the block sizes."""
     M, K = a.shape
     K2, N = b.shape
@@ -39,12 +39,8 @@ def matmul_pallas(a, b, *, bm: int = 256, bk: int = 512, bn: int = 256,
     bm, bk, bn = min(bm, M), min(bk, K), min(bn, N)
     assert M % bm == 0 and K % bk == 0 and N % bn == 0, (M, K, N, bm, bk, bn)
     out_dtype = out_dtype or a.dtype
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-    except (AttributeError, TypeError):
-        compiler_params = None
+    compiler_params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         mm_kernel,
         grid=(M // bm, N // bn, K // bk),

@@ -6,12 +6,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import use_interpret
 from repro.kernels.streamed_matmul.kernel import matmul_pallas
 from repro.kernels.streamed_matmul.ref import matmul_ref
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "use_pallas"))
@@ -30,5 +27,5 @@ def matmul(a, b, *, bm: int = 256, bk: int = 512, bn: int = 256,
     ap = jnp.pad(a, ((0, Mp - M), (0, Kp - K)))
     bp = jnp.pad(b, ((0, Kp - K), (0, Np - N)))
     out = matmul_pallas(ap, bp, bm=bm_, bk=bk_, bn=bn_,
-                        interpret=_use_interpret())
+                        interpret=use_interpret())
     return out[:M, :N]

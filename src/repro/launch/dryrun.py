@@ -66,9 +66,8 @@ def lower_cell(arch: ArchConfig, shape: ShapeConfig, mesh, plan, *,
                unroll: bool = False):
     """Lower + compile one cell's step on `mesh`. Returns (lowered, compiled).
 
-    mesh_context (jax.set_mesh on new JAX, the Mesh context manager on old)
-    activates the model's shard_hint constraints (SP residual stream,
-    seq-replicated KV); probes also unroll the flash KV-block scan.
+    mesh_context activates the model's shard_hint constraints (SP residual
+    stream, seq-replicated KV); probes also unroll the flash KV-block scan.
     """
     import repro.models.attention as attn_mod
     attn_mod.UNROLL_FLASH = unroll
@@ -98,7 +97,7 @@ def _lower_cell_inner(arch: ArchConfig, shape: ShapeConfig, mesh, plan,
         lowered = jax.jit(
             step,
             in_shardings=(psh, bsh),
-            out_shardings=(None, csh_out),
+            out_shardings=(None, None, csh_out),
         ).lower(params, input_specs(arch, shape))
     else:  # decode
         step = build_serve_step(arch, unroll=unroll)
@@ -106,7 +105,7 @@ def _lower_cell_inner(arch: ArchConfig, shape: ShapeConfig, mesh, plan,
         lowered = jax.jit(
             step,
             in_shardings=(psh, bsh, csh, scalar),
-            out_shardings=(None, csh),
+            out_shardings=(None, None, csh),
             donate_argnums=(2,),
         ).lower(params, input_specs(arch, shape), caches,
                 jax.ShapeDtypeStruct((), jnp.int32))

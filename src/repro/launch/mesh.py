@@ -1,4 +1,5 @@
-"""Production mesh construction.
+"""Mesh construction: the production mesh, the CPU test mesh, and a mesh
+over the devices that are really attached.
 
 Defined as functions (never module-level constants) so importing this module
 never touches jax device state.  The dry-run sets
@@ -8,19 +9,14 @@ smoke tests and benchmarks see the real single device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.configs.base import MeshConfig
 
-try:
-    from jax.sharding import AxisType
-except ImportError:  # jax < 0.5: all mesh axes behave as Auto
-    AxisType = None
 
-
-def _make_mesh(shape, axes) -> jax.sharding.Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def _make_mesh(shape, axes, devices=None) -> jax.sharding.Mesh:
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -34,14 +30,19 @@ def make_test_mesh(shape=(2, 4), axes=("data", "model")) -> jax.sharding.Mesh:
     return _make_mesh(shape, axes)
 
 
+def make_device_mesh(model: int = 1) -> jax.sharding.Mesh:
+    """(data=1, model) mesh over the first ``model`` attached devices:
+    ``model=1`` is one chip unsharded, ``model=4`` one v5e host's 2x2."""
+    devices = jax.devices()
+    if len(devices) < model:
+        raise ValueError(f"a model={model} mesh needs {model} devices; "
+                         f"{len(devices)} attached")
+    return _make_mesh((1, model), ("data", "model"), devices[:model])
+
+
 def mesh_context(mesh: jax.sharding.Mesh):
-    """`jax.set_mesh(mesh)` where available (>=0.5); on older JAX the Mesh
-    object itself is the context manager that activates the same
-    thread-resource state consumed by shard_hint/with_sharding_constraint."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    """Activate ``mesh`` for the model's shard_hint constraints."""
+    return jax.set_mesh(mesh)
 
 
 def mesh_config_of(mesh: jax.sharding.Mesh) -> MeshConfig:

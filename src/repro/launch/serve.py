@@ -1,11 +1,16 @@
 """Batched serving driver: prefill + decode loop with greedy sampling.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-7b --reduced \
-        --batch 4 --prompt-len 32 --gen 16
+    PYTHONPATH=src python -m repro.launch.serve --arch starcoder2-3b \
+        --batch 8 --prompt-len 512 --gen 32 [--reduced]
+
+Published widths by default; ``--reduced`` serves the arch's tiny
+same-family config (CPU tests and examples).
 
 Serve path exercises: prefill -> stacked KV caches -> decode_step loop
-(ring-buffer caches for SWA archs; recurrent state for rwkv/hymba).  The
-paged host KV tier is exercised by examples/oversubscribe_demo.py.
+(ring-buffer caches for SWA archs; recurrent state for rwkv/hymba).  Params
+and caches are created already placed by the mesh's shardings, so a model
+larger than one chip is never assembled on device 0.  The paged host KV
+tier is exercised by examples/oversubscribe_demo.py.
 """
 from __future__ import annotations
 
@@ -16,88 +21,155 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.configs import ARCH_NAMES, get_config
-from repro.launch.step import build_prefill_step, build_serve_step
-from repro.models import init_caches, init_params, prefill
+from repro.configs import ARCH_NAMES, ArchConfig, ModelConfig, ShapeConfig, get_config
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_device_mesh, mesh_context
+from repro.launch.sharding import param_shardings
+from repro.launch.step import (
+    abstract_params,
+    build_prefill_step,
+    build_serve_step,
+    make_shardings,
+)
+from repro.models import init_caches, init_params
 
 
-def serve(arch_name: str, *, reduced: bool = True, batch: int = 4,
-          prompt_len: int = 32, gen: int = 16, seed: int = 0):
+@dataclasses.dataclass(frozen=True)
+class ServeResult:
+    prompt: np.ndarray     # (B, prompt_len[, K]) the seeded prompt
+    tokens: np.ndarray     # (B, gen[, K]) greedy tokens; [:, 0] from prefill
+    logits: jax.Array      # (B, gen[, K], V) the logits each token came from
+    compile_s: float       # prefill + decode programs
+    prefill_s: float
+    ms_per_token: float    # decode steps, to block_until_ready
+
+
+def serving_arch(arch_name: str, *, reduced: bool = False,
+                 layers: int | None = None) -> ArchConfig:
+    """The arch at published widths, or its reduced config; ``layers``
+    cuts depth and nothing else."""
     arch = get_config(arch_name)
-    if reduced:
-        arch = dataclasses.replace(arch, model=arch.model.reduce())
-    cfg = arch.model
-    params = init_params(jax.random.key(seed), cfg)
-    max_seq = prompt_len + gen
+    model = arch.model.reduce() if reduced else arch.model
+    if layers is not None:
+        model = dataclasses.replace(model, num_layers=layers)
+    return dataclasses.replace(arch, model=model)
 
-    rng = np.random.default_rng(seed)
-    if cfg.family == "audio":
-        prompt = rng.integers(0, cfg.vocab_size,
-                              (batch, prompt_len, cfg.num_codebooks)).astype(np.int32)
-    elif cfg.family == "vlm":
-        prompt = rng.integers(0, cfg.vocab_size, (batch, prompt_len)).astype(np.int32)
-    else:
-        prompt = rng.integers(0, cfg.vocab_size, (batch, prompt_len)).astype(np.int32)
 
-    # prefill over the prompt, then pad/copy the caches to max_seq
-    pre_batch = {"tokens": jnp.asarray(prompt)}
-    if cfg.family == "vlm":
-        pre_batch = {"embeds": jax.random.normal(
-            jax.random.key(1), (batch, prompt_len, cfg.d_model)),
-            "labels": jnp.asarray(prompt)}
-        pre_batch.pop("labels")
-    logits_last, caches_prompt = jax.jit(
-        lambda p, b: prefill(p, b, cfg))(params, pre_batch)
+def init_placed_params(arch: ArchConfig, mesh, seed: int):
+    """Params made from ``seed`` directly into their mesh shardings."""
+    params_sh = param_shardings(arch.model, abstract_params(arch), mesh)
+    return jax.jit(init_params, static_argnums=1,
+                   out_shardings=params_sh)(jax.random.key(seed), arch.model)
 
-    caches = init_caches(cfg, batch, max_seq)
+
+def _fill_caches(caches, prompt_caches, cfg: ModelConfig):
+    """Copy the prompt's caches into the max_seq decode caches."""
     if cfg.family == "ssm":
-        caches = caches_prompt  # recurrent state is position-independent
-    else:
-        s_cache = min(caches["k"].shape[2], caches_prompt["k"].shape[2])
-        for key in ("k", "v"):
-            caches[key] = jax.lax.dynamic_update_slice_in_dim(
-                caches[key], caches_prompt[key][:, :, -s_cache:], 0, axis=2)
-        for key in ("conv", "ssm"):
-            if key in caches:
-                caches[key] = caches_prompt[key]
+        return prompt_caches  # recurrent state is position-independent
+    caches = dict(caches)
+    s_cache = min(caches["k"].shape[2], prompt_caches["k"].shape[2])
+    for key in ("k", "v"):
+        caches[key] = jax.lax.dynamic_update_slice_in_dim(
+            caches[key], prompt_caches[key][:, :, -s_cache:], 0, axis=2)
+    for key in ("conv", "ssm"):
+        if key in caches:
+            caches[key] = prompt_caches[key]
+    return caches
 
-    serve_step = jax.jit(build_serve_step(arch))
-    if cfg.family == "audio":
-        next_tokens = jnp.argmax(logits_last, axis=-1).astype(jnp.int32)  # (B,K)
-    else:
-        next_tokens = jnp.argmax(logits_last, axis=-1).astype(jnp.int32)  # (B,)
-    generated = [np.asarray(next_tokens)]
-    t0 = time.time()
-    cache_len = prompt_len
-    for i in range(gen - 1):
-        if cfg.family == "vlm":
-            step_batch = {"tokens": next_tokens,
-                          "embeds": jnp.zeros((batch, 1, cfg.d_model),
-                                              jnp.float32 if cfg.dtype != "bfloat16" else jnp.bfloat16)}
-            step_batch.pop("embeds")  # text decode goes through the embedding
-        else:
-            step_batch = {"tokens": next_tokens}
-        next_tokens, caches = serve_step(params, step_batch, caches,
-                                         jnp.int32(cache_len))
-        next_tokens = next_tokens.astype(jnp.int32)
-        generated.append(np.asarray(next_tokens))
-        cache_len += 1
-    dt = time.time() - t0
-    toks = np.stack(generated, axis=1)
-    print(f"[{arch_name}] generated {toks.shape} tokens in {dt:.2f}s "
-          f"({dt / max(gen - 1, 1) * 1e3:.1f} ms/token)")
-    return toks
+
+def serve(arch_name: str, *, reduced: bool = False, layers: int | None = None,
+          batch: int = 4, prompt_len: int = 32, gen: int = 16, seed: int = 0,
+          mesh=None) -> ServeResult:
+    """Greedy-serve ``gen`` tokens for a seeded batch of prompts.
+
+    ``mesh`` defaults to one device, unsharded; on a (data, model) mesh the
+    params and caches are sharded by ``launch/step.make_shardings``."""
+    arch = serving_arch(arch_name, reduced=reduced, layers=layers)
+    cfg = arch.model
+    mesh = mesh if mesh is not None else make_device_mesh()
+    # the caches' sequence dim is sharded over "model"; slots past
+    # cache_len are masked, so rounding up changes no result
+    model = mesh.shape["model"]
+    max_seq = -(-(prompt_len + gen) // model) * model
+    shape = ShapeConfig("serve", seq_len=max_seq, global_batch=batch,
+                        kind="decode")
+    params_sh, _, batch_sh, caches_sh = make_shardings(arch, shape, mesh)
+    tok_sh = batch_sh["tokens"]                      # (B[, K]) decode tokens
+    prompt_sh = NamedSharding(mesh, P(tok_sh.spec[0], None, *tok_sh.spec[1:]))
+    scalar_sh = NamedSharding(mesh, P())
+    params = init_placed_params(arch, mesh, seed)
+    codebooks = (cfg.num_codebooks,) if cfg.family == "audio" else ()
+    prompt = np.random.default_rng(seed).integers(
+        0, cfg.vocab_size, (batch, prompt_len) + codebooks).astype(np.int32)
+    prefill_step = build_prefill_step(arch)
+
+    def prefill_into(params, batch_, caches):
+        next_tokens, logits, prompt_caches = prefill_step(params, batch_)
+        return next_tokens, logits, _fill_caches(caches, prompt_caches, cfg)
+
+    with mesh_context(mesh):
+        caches = jax.jit(init_caches, static_argnums=(0, 1, 2),
+                         out_shardings=caches_sh)(cfg, batch, max_seq)
+        pre_batch = {"tokens": jax.device_put(prompt, prompt_sh)}
+        t0 = time.perf_counter()
+        prefill_c = jax.jit(
+            prefill_into, in_shardings=(params_sh, prompt_sh, caches_sh),
+            out_shardings=(tok_sh, None, caches_sh), donate_argnums=(2,),
+        ).lower(params, pre_batch, caches).compile()
+        step_tokens = jax.ShapeDtypeStruct(prompt.shape[:1] + prompt.shape[2:],
+                                           jnp.int32)
+        decode_c = jax.jit(
+            build_serve_step(arch),
+            in_shardings=(params_sh, tok_sh, caches_sh, scalar_sh),
+            out_shardings=(tok_sh, None, caches_sh), donate_argnums=(2,),
+        ).lower(params, {"tokens": step_tokens}, caches,
+                jax.ShapeDtypeStruct((), jnp.int32)).compile()
+        compile_s = time.perf_counter() - t0
+
+        t0 = time.perf_counter()
+        next_tokens, logits, caches = prefill_c(params, pre_batch, caches)
+        next_tokens.block_until_ready()
+        prefill_s = time.perf_counter() - t0
+
+        tokens, step_logits = [next_tokens], [logits]
+        t0 = time.perf_counter()
+        for i in range(gen - 1):
+            cache_len = jax.device_put(np.int32(prompt_len + i), scalar_sh)
+            next_tokens, logits, caches = decode_c(
+                params, {"tokens": next_tokens}, caches, cache_len)
+            tokens.append(next_tokens)
+            step_logits.append(logits)
+        jax.block_until_ready(tokens[-1])
+        decode_s = time.perf_counter() - t0
+
+    result = ServeResult(
+        prompt=prompt,
+        tokens=np.stack([np.asarray(t) for t in tokens], axis=1),
+        logits=jnp.stack(step_logits, axis=1),
+        compile_s=compile_s,
+        prefill_s=prefill_s,
+        ms_per_token=decode_s / max(gen - 1, 1) * 1e3,
+    )
+    print(f"[{arch_name}] generated {result.tokens.shape} tokens: "
+          f"compile {compile_s:.2f}s, prefill {prefill_s:.3f}s, "
+          f"{result.ms_per_token:.2f} ms/token")
+    return result
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_NAMES, default="qwen2-7b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the arch's tiny same-family config")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args()
-    serve(args.arch, batch=args.batch, prompt_len=args.prompt_len, gen=args.gen)
+    enable_compile_cache()
+    serve(args.arch, reduced=args.reduced, batch=args.batch,
+          prompt_len=args.prompt_len, gen=args.gen)
 
 
 if __name__ == "__main__":

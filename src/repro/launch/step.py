@@ -108,10 +108,8 @@ def make_shardings(arch: ArchConfig, shape: ShapeConfig, mesh,
 
     opt_sh = None
     if shape.kind == "train":
-        # None = backend-default memory ("device" on TPU/GPU).  Older
-        # XLA:CPU backends advertise no "device" kind at all, so only name a
-        # kind when the plan demands host placement AND the backend can
-        # compile it.
+        # None = backend-default memory; name a kind only when the plan
+        # demands host placement AND the backend can compile it.
         opt_kind = None
         if plan is not None and plan.opt_space is MemorySpace.HOST:
             from repro.core.placement import backend_supports_memory_kinds
@@ -256,24 +254,27 @@ def build_train_step(arch: ArchConfig, shape: ShapeConfig, mesh,
 
 
 def build_prefill_step(arch: ArchConfig, *, unroll: bool = False):
+    """Prompt pass: (params, batch) -> (greedy next tokens, last-position
+    logits, caches of the prompt's length)."""
     cfg = arch.model
 
     def prefill_step(params, batch):
         logits, caches = tf.prefill(params, batch, cfg, unroll=unroll)
         next_tokens = jnp.argmax(logits, axis=-1)
-        return next_tokens, caches
+        return next_tokens, logits, caches
 
     return prefill_step
 
 
 def build_serve_step(arch: ArchConfig, *, unroll: bool = False):
-    """One-token decode step: greedy sample + cache update."""
+    """One-token decode step: greedy sample + cache update.
+    (params, batch, caches, cache_len) -> (next tokens, logits, caches)."""
     cfg = arch.model
 
     def serve_step(params, batch, caches, cache_len):
         logits, caches = tf.decode_step(params, batch, caches, cache_len, cfg,
                                         unroll=unroll)
         next_tokens = jnp.argmax(logits, axis=-1)
-        return next_tokens, caches
+        return next_tokens, logits, caches
 
     return serve_step

@@ -1,10 +1,11 @@
 """End-to-end training driver.
 
     PYTHONPATH=src python -m repro.launch.train --arch starcoder2-3b \
-        --steps 50 --reduced [--batch 8 --seq 128] [--ckpt-dir /tmp/ckpt]
+        --steps 50 [--no-reduced] [--batch 8 --seq 128] [--ckpt-dir DIR]
 
---reduced trains the arch's reduced config on CPU (the examples/ and tests
-use this); the full config path is the same code under the production mesh.
+--reduced (the default) trains the arch's reduced config on CPU (the
+examples/ and tests use this); --no-reduced selects published widths, which
+need the optimizer offload this driver does not wire yet.
 Integrates: residency planning, UM prefetch input pipeline, AdamW(+int8),
 checkpoint/restart via TrainRunner, straggler watchdog.
 """
@@ -21,6 +22,7 @@ from repro.checkpoint import Checkpointer
 from repro.configs import ARCH_NAMES, get_config
 from repro.configs.base import ShapeConfig
 from repro.data import DataConfig, synthetic_batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.step import build_train_step
 from repro.models import init_params
 from repro.optim import init_state
@@ -79,11 +81,13 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_NAMES, default="starcoder2-3b")
     ap.add_argument("--steps", type=int, default=50)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     train(args.arch, steps=args.steps, reduced=args.reduced,
           batch=args.batch, seq=args.seq, ckpt_dir=args.ckpt_dir)
 

@@ -11,25 +11,6 @@ from jax.sharding import PartitionSpec as P
 UNC = P.UNCONSTRAINED
 
 
-def _current_mesh():
-    """Version-compat mesh lookup: `jax.sharding.get_abstract_mesh` landed
-    after 0.4.x; on older JAX fall back to the thread-resource physical mesh
-    set by `with mesh:` contexts.  Returns None when no mesh is active —
-    shard hints then degrade to no-ops, which is the single-device case."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    try:
-        from jax._src import mesh as _mesh_src
-
-        phys = _mesh_src.thread_resources.env.physical_mesh
-        if phys is not None and not phys.empty:
-            return phys
-    except Exception:
-        pass
-    return None
-
-
 BATCH = "__batch__"  # sentinel: replaced by the DP axes of the context mesh
 SEQ = "__seq__"      # sentinel: "model" under 2D (TP+SP) sharding, unsharded
                      # under pure-FSDP ("model" joins the batch axes instead)
@@ -63,8 +44,7 @@ def get_sharding_mode() -> str:
 
 
 def batch_axes_from_ctx() -> tuple[str, ...]:
-    mesh = _current_mesh()
-    names = set(getattr(mesh, "axis_names", ()) or ())
+    names = set(jax.sharding.get_abstract_mesh().axis_names)
     axes = ("pod", "data", "model") if _SHARDING_MODE == "fsdp" else ("pod", "data")
     return tuple(a for a in axes if a in names)
 
@@ -77,8 +57,7 @@ def shard_hint(x, spec: P):
     The BATCH sentinel resolves to the mesh's DP axes: UNCONSTRAINED dims are
     a GSPMD *choice*, and it will happily replicate a batch dim — batch
     sharding must be pinned explicitly."""
-    mesh = _current_mesh()
-    names = set(getattr(mesh, "axis_names", ()) or ())
+    names = set(jax.sharding.get_abstract_mesh().axis_names)
     if not names:
         return x
     resolved = []

@@ -54,7 +54,7 @@ for name in ("qwen2-7b", "rwkv6-3b", "mixtral-8x22b"):
         sstep = build_serve_step(arch)
         comp = jax.jit(sstep,
                        in_shardings=(psh, bsh, csh, NamedSharding(mesh, P())),
-                       out_shardings=(None, csh), donate_argnums=(2,)).lower(
+                       out_shardings=(None, None, csh), donate_argnums=(2,)).lower(
             abstract_params(arch), input_specs(arch, shape_d),
             abstract_caches(arch, shape_d), jax.ShapeDtypeStruct((), jnp.int32)
         ).compile()
