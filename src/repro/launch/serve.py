@@ -16,7 +16,6 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +26,7 @@ from repro.configs import ARCH_NAMES, ArchConfig, ModelConfig, ShapeConfig, get_
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_device_mesh, mesh_context
 from repro.launch.sharding import param_shardings
+from repro.launch.spans import SpanRecorder
 from repro.launch.step import (
     abstract_params,
     build_prefill_step,
@@ -44,6 +44,7 @@ class ServeResult:
     compile_s: float       # prefill + decode programs
     prefill_s: float
     ms_per_token: float    # decode steps, to block_until_ready
+    spans: dict[str, tuple[int, float]]  # (count, seconds) of each serve* span
 
 
 def serving_arch(arch_name: str, *, reduced: bool = False,
@@ -86,76 +87,86 @@ def serve(arch_name: str, *, reduced: bool = False, layers: int | None = None,
 
     ``mesh`` defaults to one device, unsharded; on a (data, model) mesh the
     params and caches are sharded by ``launch/step.make_shardings``."""
-    arch = serving_arch(arch_name, reduced=reduced, layers=layers)
-    cfg = arch.model
-    mesh = mesh if mesh is not None else make_device_mesh()
-    # the caches' sequence dim is sharded over "model"; slots past
-    # cache_len are masked, so rounding up changes no result
-    model = mesh.shape["model"]
-    max_seq = -(-(prompt_len + gen) // model) * model
-    shape = ShapeConfig("serve", seq_len=max_seq, global_batch=batch,
-                        kind="decode")
-    params_sh, _, batch_sh, caches_sh = make_shardings(arch, shape, mesh)
-    tok_sh = batch_sh["tokens"]                      # (B[, K]) decode tokens
-    prompt_sh = NamedSharding(mesh, P(tok_sh.spec[0], None, *tok_sh.spec[1:]))
-    scalar_sh = NamedSharding(mesh, P())
-    params = init_placed_params(arch, mesh, seed)
-    codebooks = (cfg.num_codebooks,) if cfg.family == "audio" else ()
-    prompt = np.random.default_rng(seed).integers(
-        0, cfg.vocab_size, (batch, prompt_len) + codebooks).astype(np.int32)
-    prefill_step = build_prefill_step(arch)
+    rec = SpanRecorder()
+    with rec.span("serve", seed=seed, batch=batch, prompt_len=prompt_len, gen=gen):
+        arch = serving_arch(arch_name, reduced=reduced, layers=layers)
+        cfg = arch.model
+        mesh = mesh if mesh is not None else make_device_mesh()
+        with rec.span("serve.init"):
+            # the caches' sequence dim is sharded over "model"; slots past
+            # cache_len are masked, so rounding up changes no result
+            model = mesh.shape["model"]
+            max_seq = -(-(prompt_len + gen) // model) * model
+            shape = ShapeConfig("serve", seq_len=max_seq, global_batch=batch,
+                                kind="decode")
+            params_sh, _, batch_sh, caches_sh = make_shardings(arch, shape, mesh)
+            tok_sh = batch_sh["tokens"]                  # (B[, K]) decode tokens
+            prompt_sh = NamedSharding(mesh, P(tok_sh.spec[0], None, *tok_sh.spec[1:]))
+            scalar_sh = NamedSharding(mesh, P())
+            params = init_placed_params(arch, mesh, seed)
+            codebooks = (cfg.num_codebooks,) if cfg.family == "audio" else ()
+            prompt = np.random.default_rng(seed).integers(
+                0, cfg.vocab_size, (batch, prompt_len) + codebooks).astype(np.int32)
+            with mesh_context(mesh):
+                caches = jax.jit(init_caches, static_argnums=(0, 1, 2),
+                                 out_shardings=caches_sh)(cfg, batch, max_seq)
+                pre_batch = {"tokens": jax.device_put(prompt, prompt_sh)}
+        prefill_step = build_prefill_step(arch)
 
-    def prefill_into(params, batch_, caches):
-        next_tokens, logits, prompt_caches = prefill_step(params, batch_)
-        return next_tokens, logits, _fill_caches(caches, prompt_caches, cfg)
+        def prefill_into(params, batch_, caches):
+            next_tokens, logits, prompt_caches = prefill_step(params, batch_)
+            return next_tokens, logits, _fill_caches(caches, prompt_caches, cfg)
 
-    with mesh_context(mesh):
-        caches = jax.jit(init_caches, static_argnums=(0, 1, 2),
-                         out_shardings=caches_sh)(cfg, batch, max_seq)
-        pre_batch = {"tokens": jax.device_put(prompt, prompt_sh)}
-        t0 = time.perf_counter()
-        prefill_c = jax.jit(
-            prefill_into, in_shardings=(params_sh, prompt_sh, caches_sh),
-            out_shardings=(tok_sh, None, caches_sh), donate_argnums=(2,),
-        ).lower(params, pre_batch, caches).compile()
-        step_tokens = jax.ShapeDtypeStruct(prompt.shape[:1] + prompt.shape[2:],
-                                           jnp.int32)
-        decode_c = jax.jit(
-            build_serve_step(arch),
-            in_shardings=(params_sh, tok_sh, caches_sh, scalar_sh),
-            out_shardings=(tok_sh, None, caches_sh), donate_argnums=(2,),
-        ).lower(params, {"tokens": step_tokens}, caches,
-                jax.ShapeDtypeStruct((), jnp.int32)).compile()
-        compile_s = time.perf_counter() - t0
+        with mesh_context(mesh):
+            with rec.span("serve.lower.prefill"):
+                prefill_l = jax.jit(
+                    prefill_into, in_shardings=(params_sh, prompt_sh, caches_sh),
+                    out_shardings=(tok_sh, None, caches_sh), donate_argnums=(2,),
+                ).lower(params, pre_batch, caches)
+            with rec.span("serve.compile.prefill"):
+                prefill_c = prefill_l.compile()
+            with rec.span("serve.lower.decode"):
+                step_tokens = jax.ShapeDtypeStruct(
+                    prompt.shape[:1] + prompt.shape[2:], jnp.int32)
+                decode_l = jax.jit(
+                    build_serve_step(arch),
+                    in_shardings=(params_sh, tok_sh, caches_sh, scalar_sh),
+                    out_shardings=(tok_sh, None, caches_sh), donate_argnums=(2,),
+                ).lower(params, {"tokens": step_tokens}, caches,
+                        jax.ShapeDtypeStruct((), jnp.int32))
+            with rec.span("serve.compile.decode"):
+                decode_c = decode_l.compile()
 
-        t0 = time.perf_counter()
-        next_tokens, logits, caches = prefill_c(params, pre_batch, caches)
-        next_tokens.block_until_ready()
-        prefill_s = time.perf_counter() - t0
+            with rec.span("serve.prefill"):
+                next_tokens, logits, caches = prefill_c(params, pre_batch, caches)
+                next_tokens.block_until_ready()
 
-        tokens, step_logits = [next_tokens], [logits]
-        t0 = time.perf_counter()
-        for i in range(gen - 1):
-            cache_len = jax.device_put(np.int32(prompt_len + i), scalar_sh)
-            next_tokens, logits, caches = decode_c(
-                params, {"tokens": next_tokens}, caches, cache_len)
-            tokens.append(next_tokens)
-            step_logits.append(logits)
-        jax.block_until_ready(tokens[-1])
-        decode_s = time.perf_counter() - t0
+            tokens, step_logits = [next_tokens], [logits]
+            with rec.span("serve.decode"):
+                for i in range(gen - 1):
+                    with rec.span("serve.decode_step"):
+                        cache_len = jax.device_put(np.int32(prompt_len + i), scalar_sh)
+                        next_tokens, logits, caches = decode_c(
+                            params, {"tokens": next_tokens}, caches, cache_len)
+                    tokens.append(next_tokens)
+                    step_logits.append(logits)
+                jax.block_until_ready(tokens[-1])
 
-    result = ServeResult(
+        with rec.span("serve.gather"):
+            tokens = np.stack([np.asarray(t) for t in tokens], axis=1)
+            logits = jnp.stack(step_logits, axis=1)
+
+    s = rec.seconds
+    return ServeResult(
         prompt=prompt,
-        tokens=np.stack([np.asarray(t) for t in tokens], axis=1),
-        logits=jnp.stack(step_logits, axis=1),
-        compile_s=compile_s,
-        prefill_s=prefill_s,
-        ms_per_token=decode_s / max(gen - 1, 1) * 1e3,
+        tokens=tokens,
+        logits=logits,
+        compile_s=sum(s[f"serve.{step}.{prog}"] for step in ("lower", "compile")
+                      for prog in ("prefill", "decode")),
+        prefill_s=s["serve.prefill"],
+        ms_per_token=s["serve.decode"] / max(gen - 1, 1) * 1e3,
+        spans=rec.totals(),
     )
-    print(f"[{arch_name}] generated {result.tokens.shape} tokens: "
-          f"compile {compile_s:.2f}s, prefill {prefill_s:.3f}s, "
-          f"{result.ms_per_token:.2f} ms/token")
-    return result
 
 
 def main() -> None:
@@ -168,8 +179,13 @@ def main() -> None:
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args()
     enable_compile_cache()
-    serve(args.arch, reduced=args.reduced, batch=args.batch,
-          prompt_len=args.prompt_len, gen=args.gen)
+    result = serve(args.arch, reduced=args.reduced, batch=args.batch,
+                   prompt_len=args.prompt_len, gen=args.gen)
+    print(f"[{args.arch}] generated {result.tokens.shape} tokens: "
+          f"compile {result.compile_s:.2f}s, prefill {result.prefill_s:.3f}s, "
+          f"{result.ms_per_token:.2f} ms/token")
+    for name, (count, secs) in result.spans.items():
+        print(f"  {name:<22} {count:>5} x  {secs * 1e3:10.2f} ms")
 
 
 if __name__ == "__main__":
