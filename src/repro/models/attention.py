@@ -23,16 +23,6 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def repeat_kv(k, groups: int):
-    """(B,S,Hkv,Dh) -> (B,S,Hkv*groups,Dh)."""
-    if groups == 1:
-        return k
-    b, s, h, d = k.shape
-    return jnp.broadcast_to(k[:, :, :, None, :], (b, s, h, groups, d)).reshape(
-        b, s, h * groups, d
-    )
-
-
 def causal_mask(q_len: int, kv_len: int, *, window: int | None = None, q_offset=0):
     """(q_len, kv_len) bool mask; True = attend."""
     qi = jnp.arange(q_len)[:, None] + q_offset
@@ -44,8 +34,8 @@ def causal_mask(q_len: int, kv_len: int, *, window: int | None = None, q_offset=
 
 
 def _attention_dense(q, k, v, *, causal, window, q_offset, mask, scale):
-    """Grouped-GQA dense attention: no repeat_kv materialization — scores are
-    computed per kv-head group: (B, Hkv, G, Sq, Skv)."""
+    """Grouped-GQA dense attention: K/V are never repeated to the query
+    heads — scores are computed per kv-head group: (B, Hkv, G, Sq, Skv)."""
     b, sq, hq, dh = q.shape
     hkv = k.shape[2]
     g = hq // hkv
@@ -184,20 +174,23 @@ def decode_attention_partial(q, k, v, valid_mask, softmax_scale: float | None = 
     q: (B,Hq,Dh); k/v: (B,Skv,Hkv,Dh); valid_mask: (B,Skv) bool.
     Returns partials (numerator (B,Hq,Dh) fp32, denominator (B,Hq) fp32,
     running max (B,Hq) fp32) that combine exactly across shards.
+
+    Query heads are grouped per KV head, G = Hq // Hkv, so each KV head is
+    read once for its G query heads; K/V are never repeated to Hq heads.
     """
     b, hq, dh = q.shape
     hkv = k.shape[2]
-    k = repeat_kv(k, hq // hkv)
-    v = repeat_kv(v, hq // hkv)
+    qg = q.reshape(b, hkv, hq // hkv, dh)
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(dh)
-    logits = jnp.einsum("bhd,bkhd->bhk", q, k).astype(jnp.float32) * scale
-    logits = jnp.where(valid_mask[:, None, :], logits, NEG_INF)
-    m = jnp.max(logits, axis=-1)                       # (B,Hq)
-    p = jnp.exp(logits - m[..., None])                 # (B,Hq,Skv)
-    p = jnp.where(valid_mask[:, None, :], p, 0.0)
-    denom = jnp.sum(p, axis=-1)                        # (B,Hq)
-    num = jnp.einsum("bhk,bkhd->bhd", p.astype(v.dtype), v).astype(jnp.float32)
-    return num, denom, m
+    logits = jnp.einsum("bkgd,bskd->bkgs", qg, k).astype(jnp.float32) * scale
+    mask = valid_mask[:, None, None, :]
+    logits = jnp.where(mask, logits, NEG_INF)
+    m = jnp.max(logits, axis=-1)                       # (B,Hkv,G)
+    p = jnp.exp(logits - m[..., None])                 # (B,Hkv,G,Skv)
+    p = jnp.where(mask, p, 0.0)
+    denom = jnp.sum(p, axis=-1)                        # (B,Hkv,G)
+    num = jnp.einsum("bkgs,bskd->bkgd", p.astype(v.dtype), v).astype(jnp.float32)
+    return num.reshape(b, hq, dh), denom.reshape(b, hq), m.reshape(b, hq)
 
 
 def combine_decode_partials(num, denom, m, axis_name: str | None):

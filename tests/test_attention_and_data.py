@@ -1,5 +1,7 @@
 """Attention math invariants (split-KV decode, flash vs dense) + data
 pipeline determinism/prefetch."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,6 +15,8 @@ except ImportError:  # collection must not error (dev-only dependency)
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig
 from repro.data import DataConfig, prefetched, synthetic_batches
+from repro.launch.step import (abstract_caches, abstract_params,
+                               build_serve_step, input_specs)
 from repro.models.attention import (
     attention,
     attention_flash,
@@ -22,10 +26,48 @@ from repro.models.attention import (
 )
 
 
-def test_split_kv_decode_equals_full(key):
+HEAD_PAIRS = [(4, 4), (4, 2), (24, 2), (8, 1)]   # (Hq, Hkv): MHA, GQA, MQA
+
+
+@jax.jit
+def _decode_oracle(q, k, v, valid):
+    """Plain float32 one-token attention: K/V repeated to every query head,
+    one softmax per head over the valid positions."""
+    g = q.shape[1] // k.shape[2]
+    k = jnp.repeat(k, g, axis=2).astype(jnp.float32)
+    v = jnp.repeat(v, g, axis=2).astype(jnp.float32)
+    s = jnp.einsum("bhd,bshd->bhs", q.astype(jnp.float32), k,
+                   precision="highest") / np.sqrt(q.shape[-1])
+    s = jnp.where(valid[:, None, :], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhs,bshd->bhd", p, v, precision="highest")
+
+
+@pytest.mark.parametrize("hq,hkv", HEAD_PAIRS)
+def test_grouped_decode_matches_repeated_oracle(key, hq, hkv):
+    """Grouped decode attention (each KV head read once for its G query
+    heads) equals attention over K/V repeated to every query head."""
+    B, S, Dh = 3, 40, 16
+    ks = jax.random.split(key, 4)
+    q = jax.random.normal(ks[0], (B, hq, Dh))
+    k = jax.random.normal(ks[1], (B, S, hkv, Dh))
+    v = jax.random.normal(ks[2], (B, S, hkv, Dh))
+    # partly valid rows, each with at least one valid position
+    valid = jax.random.bernoulli(ks[3], 0.6, (B, S)).at[:, 0].set(True)
+    num, den, m = jax.jit(decode_attention_partial)(q, k, v, valid)
+    assert num.shape == (B, hq, Dh) and den.shape == m.shape == (B, hq)
+    assert num.dtype == den.dtype == m.dtype == jnp.float32
+    out = combine_decode_partials(num, den, m, None)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_decode_oracle(q, k, v, valid)),
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("hq,hkv", HEAD_PAIRS)
+def test_split_kv_decode_equals_full(key, hq, hkv):
     """Partial-softmax shards combine to the exact full attention (the
     flash-decoding combine used for seq-sharded KV decode)."""
-    B, S, Hq, Hkv, Dh = 2, 64, 4, 2, 16
+    B, S, Hq, Hkv, Dh = 2, 64, hq, hkv, 16
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (B, Hq, Dh))
     k = jax.random.normal(ks[1], (B, S, Hkv, Dh))
@@ -57,6 +99,27 @@ def test_decode_attention_masks_beyond_cache_len(key):
     out_poison = decode_attention(q, k2, v2, jnp.int32(10))
     np.testing.assert_allclose(np.asarray(out_short), np.asarray(out_poison),
                                atol=1e-6)
+
+
+@pytest.mark.parametrize("arch_name", ["starcoder2-3b", "mixtral-8x22b"])
+def test_decode_step_never_repeats_kv(arch_name):
+    """The lowered serve step (full cache; ring-buffered sliding window) holds
+    no K/V broadcast to the query heads, (B,Smax,Hkv,G,Dh) or (B,Smax,Hq,Dh),
+    only the grouped (B,Hkv,G,Smax) scores."""
+    arch = get_config(arch_name)
+    model = dataclasses.replace(arch.model.reduce(), num_heads=8, num_kv_heads=2)
+    arch = dataclasses.replace(arch, model=model)
+    B, S, G, Dh = 3, 64, 4, 16
+    assert model.head_dim == Dh
+    shape = ShapeConfig("d", seq_len=S, global_batch=B, kind="decode")
+    caches = abstract_caches(arch, shape)
+    assert caches["k"].shape[1:] == (B, S, 2, Dh)
+    text = jax.jit(build_serve_step(arch)).lower(
+        abstract_params(arch), input_specs(arch, shape), caches,
+        jax.ShapeDtypeStruct((), jnp.int32)).as_text()
+    assert f"tensor<{B}x2x{G}x{S}xf32>" in text
+    assert f"tensor<{B}x{S}x2x{G}x{Dh}x" not in text
+    assert f"tensor<{B}x{S}x8x{Dh}x" not in text
 
 
 @settings(max_examples=15, deadline=None)
