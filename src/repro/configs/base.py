@@ -31,6 +31,9 @@ class ModelConfig:
     qkv_bias: bool = False
     rope: Literal["rope", "mrope", "none"] = "rope"
     rope_theta: float = 10_000.0
+    # share of each head's dims that RoPE rotates (the first ones); the
+    # rest pass unrotated (NeMo ``rotary_percent``, HF ``partial_rotary_factor``)
+    partial_rotary_factor: float = 1.0
     # MoE
     num_experts: int = 0      # 0 => dense FFN
     top_k: int = 0
@@ -48,6 +51,14 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0 and self.num_heads > 0:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        if self.num_heads and (self.rotary_dim % 2 or self.rotary_dim <= 0):
+            raise ValueError(f"rotary dims {self.rotary_dim} of head_dim "
+                             f"{self.head_dim}: must be even and positive")
+
+    @property
+    def rotary_dim(self) -> int:
+        """Leading dims of each query and key head that RoPE rotates."""
+        return int(self.partial_rotary_factor * self.head_dim)
 
     @property
     def padded_vocab(self) -> int:

@@ -149,10 +149,20 @@ def apply_rotary(x, cos, sin):
     return out.astype(x.dtype)
 
 
-def apply_rope(q, k, positions, theta: float):
-    """Standard RoPE. positions: (B, S)."""
-    cos, sin = rope_angles(positions, q.shape[-1], theta)
-    return apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
+def apply_rope(q, k, positions, theta: float, rot: int | None = None):
+    """Standard RoPE. positions: (B, S). With ``rot`` below the head size
+    only the first ``rot`` dims of each head rotate, at frequencies
+    theta^(-i/(rot/2)), and the rest pass unchanged (partial rotary)."""
+    dh = q.shape[-1]
+    rot = dh if rot is None else rot
+    cos, sin = rope_angles(positions, rot, theta)
+    if rot == dh:
+        return apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
+
+    def partial(x):
+        return jnp.concatenate([apply_rotary(x[..., :rot], cos, sin), x[..., rot:]],
+                               axis=-1)
+    return partial(q), partial(k)
 
 
 # M-RoPE (Qwen2-VL, arXiv:2409.12191): the head_dim is split into three

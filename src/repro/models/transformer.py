@@ -147,14 +147,20 @@ def _project_qkv(p, x, cfg: ModelConfig):
     return q, k, v
 
 
+def _position_embed(q, k, positions, cfg: ModelConfig):
+    """Rotary positions on q and k (B, S, H, Dh), as the config states."""
+    if cfg.rope == "rope":
+        return apply_rope(q, k, positions, cfg.rope_theta, cfg.rotary_dim)
+    if cfg.rope == "mrope":
+        return apply_mrope(q, k, positions, cfg.rope_theta)
+    return q, k
+
+
 def attn_sublayer(p, x, cfg: ModelConfig, positions, *, return_kv=False,
                   mode: str = "train"):
     B, S, _ = x.shape
     q, k, v = _project_qkv(p, x, cfg)
-    if cfg.rope == "rope":
-        q, k = apply_rope(q, k, positions, cfg.rope_theta)
-    elif cfg.rope == "mrope":
-        q, k = apply_mrope(q, k, positions, cfg.rope_theta)
+    q, k = _position_embed(q, k, positions, cfg)
     # SP attention: q stays sequence-sharded; K/V replicate along seq so the
     # score matrix shards on the query dim for any head count (GQA kv=2..24)
     q = shard_hint(q, P(BATCH, SEQ, UNC, UNC))
@@ -375,10 +381,7 @@ def _decode_attn(p, h, cfg: ModelConfig, cache, cache_len, positions):
     """
     B = h.shape[0]
     q, k_new, v_new = _project_qkv(p, h, cfg)
-    if cfg.rope == "rope":
-        q, k_new = apply_rope(q, k_new, positions, cfg.rope_theta)
-    elif cfg.rope == "mrope":
-        q, k_new = apply_mrope(q, k_new, positions, cfg.rope_theta)
+    q, k_new = _position_embed(q, k_new, positions, cfg)
     S_cache = cache["k"].shape[1]
     if cfg.sliding_window is not None and S_cache == cfg.sliding_window:
         slot = jnp.mod(cache_len, S_cache)
