@@ -11,11 +11,17 @@ Serve path exercises: prefill -> stacked KV caches -> decode_step loop
 and caches are created already placed by the mesh's shardings, so a model
 larger than one chip is never assembled on device 0.  The paged host KV
 tier is exercised by examples/oversubscribe_demo.py.
+
+The prefill and decode programs are compiled once per key (the model config,
+batch, prompt length, cache length, mesh, and the globals of the package's
+modules, the sharding mode among them) and reused by every later call in the
+process with that key; weights and caches are still made anew by each call.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -41,7 +47,7 @@ class ServeResult:
     prompt: np.ndarray     # (B, prompt_len[, K]) the seeded prompt
     tokens: np.ndarray     # (B, gen[, K]) greedy tokens; [:, 0] from prefill
     logits: jax.Array      # (B, gen[, K], V) the logits each token came from
-    compile_s: float       # prefill + decode programs
+    compile_s: float       # lowering + compiling both programs; 0 on a reuse
     prefill_s: float
     ms_per_token: float    # decode steps, to block_until_ready
     spans: dict[str, tuple[int, float]]  # (count, seconds) of each serve* span
@@ -80,6 +86,88 @@ def _fill_caches(caches, prompt_caches, cfg: ModelConfig):
     return caches
 
 
+# Compiled (prefill, decode) pairs, executables only and never arrays, keyed
+# by the model config, (batch, prompt_len, max_seq), the mesh and the globals
+# of the package's modules (`_module_globals`). Past _PROGRAMS_KEPT keys the
+# oldest is dropped.
+_PROGRAMS: dict[tuple, tuple] = {}
+_PROGRAMS_KEPT = 8
+
+
+class _Same:
+    """Equal to another ``_Same`` of the very same object, and holding that
+    object, so that its id is not handed to a later one."""
+    __slots__ = ("obj",)
+
+    def __init__(self, obj):
+        self.obj = obj
+
+    def __eq__(self, other):
+        return isinstance(other, _Same) and self.obj is other.obj
+
+    def __hash__(self):
+        return id(self.obj)
+
+
+_PLAIN = (bool, int, float, str, type(None))
+
+
+def _module_globals() -> tuple:
+    """Every global of every loaded ``repro`` module: plain constants by
+    value, anything else by identity. Tracing reads these (the sharding mode,
+    tuning constants such as ``moe.MOE_GROUP_SIZE``, the layer functions a
+    step calls), so rebinding any of them gives another key."""
+    return tuple(
+        (name, attr, value if type(value) in _PLAIN else _Same(value))
+        for name, mod in sorted(sys.modules.items())
+        if (name == "repro" or name.startswith("repro.")) and mod is not None
+        for attr, value in list(vars(mod).items()))
+
+
+def _step_programs(rec: SpanRecorder, arch: ArchConfig, mesh,
+                   shape: tuple[int, int, int], shardings, params, pre_batch, caches):
+    """The compiled ``prefill_into`` and ``serve_step`` for ``arch.model`` at
+    ``shape`` (batch, prompt_len, max_seq) on ``mesh``: lowered with their
+    shardings, donating the caches, on the first call with their key, and
+    reused after it. The closures capture the config only."""
+    key = (arch.model, shape, mesh)
+    found = _PROGRAMS.get(key + _module_globals())
+    if found is not None:
+        return found
+    cfg = arch.model
+    params_sh, prompt_sh, tok_sh, caches_sh, scalar_sh = shardings
+    prefill_step = build_prefill_step(arch)
+
+    def prefill_into(params, batch_, caches):
+        next_tokens, logits, prompt_caches = prefill_step(params, batch_)
+        return next_tokens, logits, _fill_caches(caches, prompt_caches, cfg)
+
+    with rec.span("serve.lower.prefill"):
+        prefill_l = jax.jit(
+            prefill_into, in_shardings=(params_sh, prompt_sh, caches_sh),
+            out_shardings=(tok_sh, None, caches_sh), donate_argnums=(2,),
+        ).lower(params, pre_batch, caches)
+    with rec.span("serve.compile.prefill"):
+        prefill_c = prefill_l.compile()
+    with rec.span("serve.lower.decode"):
+        prompt = pre_batch["tokens"]
+        step_tokens = jax.ShapeDtypeStruct(
+            prompt.shape[:1] + prompt.shape[2:], jnp.int32)
+        decode_l = jax.jit(
+            build_serve_step(arch),
+            in_shardings=(params_sh, tok_sh, caches_sh, scalar_sh),
+            out_shardings=(tok_sh, None, caches_sh), donate_argnums=(2,),
+        ).lower(params, {"tokens": step_tokens}, caches,
+                jax.ShapeDtypeStruct((), jnp.int32))
+    with rec.span("serve.compile.decode"):
+        decode_c = decode_l.compile()
+    # read again: tracing may have imported a module of the package
+    _PROGRAMS[key + _module_globals()] = prefill_c, decode_c
+    if len(_PROGRAMS) > _PROGRAMS_KEPT:
+        del _PROGRAMS[next(iter(_PROGRAMS))]
+    return prefill_c, decode_c
+
+
 def serve(arch_name: str, *, reduced: bool = False, layers: int | None = None,
           batch: int = 4, prompt_len: int = 32, gen: int = 16, seed: int = 0,
           mesh=None) -> ServeResult:
@@ -111,31 +199,13 @@ def serve(arch_name: str, *, reduced: bool = False, layers: int | None = None,
                 caches = jax.jit(init_caches, static_argnums=(0, 1, 2),
                                  out_shardings=caches_sh)(cfg, batch, max_seq)
                 pre_batch = {"tokens": jax.device_put(prompt, prompt_sh)}
-        prefill_step = build_prefill_step(arch)
-
-        def prefill_into(params, batch_, caches):
-            next_tokens, logits, prompt_caches = prefill_step(params, batch_)
-            return next_tokens, logits, _fill_caches(caches, prompt_caches, cfg)
-
+            # the init's device work ends in its own span, not in the prefill's
+            jax.block_until_ready((params, caches, pre_batch))
         with mesh_context(mesh):
-            with rec.span("serve.lower.prefill"):
-                prefill_l = jax.jit(
-                    prefill_into, in_shardings=(params_sh, prompt_sh, caches_sh),
-                    out_shardings=(tok_sh, None, caches_sh), donate_argnums=(2,),
-                ).lower(params, pre_batch, caches)
-            with rec.span("serve.compile.prefill"):
-                prefill_c = prefill_l.compile()
-            with rec.span("serve.lower.decode"):
-                step_tokens = jax.ShapeDtypeStruct(
-                    prompt.shape[:1] + prompt.shape[2:], jnp.int32)
-                decode_l = jax.jit(
-                    build_serve_step(arch),
-                    in_shardings=(params_sh, tok_sh, caches_sh, scalar_sh),
-                    out_shardings=(tok_sh, None, caches_sh), donate_argnums=(2,),
-                ).lower(params, {"tokens": step_tokens}, caches,
-                        jax.ShapeDtypeStruct((), jnp.int32))
-            with rec.span("serve.compile.decode"):
-                decode_c = decode_l.compile()
+            prefill_c, decode_c = _step_programs(
+                rec, arch, mesh, (batch, prompt_len, max_seq),
+                (params_sh, prompt_sh, tok_sh, caches_sh, scalar_sh),
+                params, pre_batch, caches)
 
             with rec.span("serve.prefill"):
                 next_tokens, logits, caches = prefill_c(params, pre_batch, caches)
@@ -161,8 +231,8 @@ def serve(arch_name: str, *, reduced: bool = False, layers: int | None = None,
         prompt=prompt,
         tokens=tokens,
         logits=logits,
-        compile_s=sum(s[f"serve.{step}.{prog}"] for step in ("lower", "compile")
-                      for prog in ("prefill", "decode")),
+        compile_s=sum(secs for name, secs in s.items()
+                      if name.startswith(("serve.lower.", "serve.compile."))),
         prefill_s=s["serve.prefill"],
         ms_per_token=s["serve.decode"] / max(gen - 1, 1) * 1e3,
         spans=rec.totals(),
