@@ -67,10 +67,9 @@ def lower_cell(arch: ArchConfig, shape: ShapeConfig, mesh, plan, *,
     """Lower + compile one cell's step on `mesh`. Returns (lowered, compiled).
 
     mesh_context activates the model's shard_hint constraints (SP residual
-    stream, seq-replicated KV); probes also unroll the flash KV-block scan.
+    stream, seq-replicated KV); probes also unroll the flash KV-block scan
+    (``unroll`` reaches it through ``build_prefill_step``).
     """
-    import repro.models.attention as attn_mod
-    attn_mod.UNROLL_FLASH = unroll
     with mesh_context(mesh):
         return _lower_cell_inner(arch, shape, mesh, plan, unroll)
 

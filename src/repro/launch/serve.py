@@ -14,8 +14,10 @@ tier is exercised by examples/oversubscribe_demo.py.
 
 The prefill and decode programs are compiled once per key (the model config,
 batch, prompt length, cache length, mesh, and the globals of the package's
-modules, the sharding mode among them) and reused by every later call in the
-process with that key; weights and caches are still made anew by each call.
+modules: tuning constants, and functions that code in the same process may
+rebind) and reused by every later call in the process with that key; weights
+and caches are still made anew by each call. The key is looked up inside the
+call's ``serve.init`` span; only a miss opens the lowering and compiling spans.
 """
 from __future__ import annotations
 
@@ -87,8 +89,9 @@ def _fill_caches(caches, prompt_caches, cfg: ModelConfig):
 
 
 # Compiled (prefill, decode) pairs, executables only and never arrays, keyed
-# by the model config, (batch, prompt_len, max_seq), the mesh and the globals
-# of the package's modules (`_module_globals`). Past _PROGRAMS_KEPT keys the
+# by `_program_key`: the model config, (batch, prompt_len, max_seq), the mesh
+# and the globals of the package's modules, so that a tuning constant or a
+# function rebound in-process gives another key. Past _PROGRAMS_KEPT keys the
 # oldest is dropped.
 _PROGRAMS: dict[tuple, tuple] = {}
 _PROGRAMS_KEPT = 8
@@ -114,9 +117,9 @@ _PLAIN = (bool, int, float, str, type(None))
 
 def _module_globals() -> tuple:
     """Every global of every loaded ``repro`` module: plain constants by
-    value, anything else by identity. Tracing reads these (the sharding mode,
-    tuning constants such as ``moe.MOE_GROUP_SIZE``, the layer functions a
-    step calls), so rebinding any of them gives another key."""
+    value, anything else by identity. Tracing reads these (tuning constants
+    such as ``moe.MOE_GROUP_SIZE``, the layer functions a step calls), so
+    rebinding any of them gives another key."""
     return tuple(
         (name, attr, value if type(value) in _PLAIN else _Same(value))
         for name, mod in sorted(sys.modules.items())
@@ -124,16 +127,18 @@ def _module_globals() -> tuple:
         for attr, value in list(vars(mod).items()))
 
 
+def _program_key(arch: ArchConfig, mesh, shape: tuple[int, int, int]) -> tuple:
+    """The program table's key for ``arch.model`` at ``shape`` (batch,
+    prompt_len, max_seq) on ``mesh``, read from the globals as they stand."""
+    return (arch.model, shape, mesh) + _module_globals()
+
+
 def _step_programs(rec: SpanRecorder, arch: ArchConfig, mesh,
                    shape: tuple[int, int, int], shardings, params, pre_batch, caches):
-    """The compiled ``prefill_into`` and ``serve_step`` for ``arch.model`` at
-    ``shape`` (batch, prompt_len, max_seq) on ``mesh``: lowered with their
-    shardings, donating the caches, on the first call with their key, and
-    reused after it. The closures capture the config only."""
-    key = (arch.model, shape, mesh)
-    found = _PROGRAMS.get(key + _module_globals())
-    if found is not None:
-        return found
+    """Lower and compile ``prefill_into`` and ``serve_step`` for
+    ``arch.model`` at ``shape`` on ``mesh``, with their shardings, donating
+    the caches, and keep them in the table. The closures capture the config
+    only."""
     cfg = arch.model
     params_sh, prompt_sh, tok_sh, caches_sh, scalar_sh = shardings
     prefill_step = build_prefill_step(arch)
@@ -162,7 +167,7 @@ def _step_programs(rec: SpanRecorder, arch: ArchConfig, mesh,
     with rec.span("serve.compile.decode"):
         decode_c = decode_l.compile()
     # read again: tracing may have imported a module of the package
-    _PROGRAMS[key + _module_globals()] = prefill_c, decode_c
+    _PROGRAMS[_program_key(arch, mesh, shape)] = prefill_c, decode_c
     if len(_PROGRAMS) > _PROGRAMS_KEPT:
         del _PROGRAMS[next(iter(_PROGRAMS))]
     return prefill_c, decode_c
@@ -199,13 +204,17 @@ def serve(arch_name: str, *, reduced: bool = False, layers: int | None = None,
                 caches = jax.jit(init_caches, static_argnums=(0, 1, 2),
                                  out_shardings=caches_sh)(cfg, batch, max_seq)
                 pre_batch = {"tokens": jax.device_put(prompt, prompt_sh)}
+            programs = _PROGRAMS.get(
+                _program_key(arch, mesh, (batch, prompt_len, max_seq)))
             # the init's device work ends in its own span, not in the prefill's
             jax.block_until_ready((params, caches, pre_batch))
         with mesh_context(mesh):
-            prefill_c, decode_c = _step_programs(
-                rec, arch, mesh, (batch, prompt_len, max_seq),
-                (params_sh, prompt_sh, tok_sh, caches_sh, scalar_sh),
-                params, pre_batch, caches)
+            if programs is None:
+                programs = _step_programs(
+                    rec, arch, mesh, (batch, prompt_len, max_seq),
+                    (params_sh, prompt_sh, tok_sh, caches_sh, scalar_sh),
+                    params, pre_batch, caches)
+            prefill_c, decode_c = programs
 
             with rec.span("serve.prefill"):
                 next_tokens, logits, caches = prefill_c(params, pre_batch, caches)

@@ -93,93 +93,17 @@ def _path_str(path) -> str:
     return "/".join(parts)
 
 
-def _param_rule_fsdp(shape, mesh_total: int) -> P:
-    """Pure-FSDP: shard the largest evenly-divisible dim over (data, model)
-    jointly; replicate vectors/scalars (ZeRO-3 over the full mesh)."""
-    if len(shape) < 2:
-        return P(*(None,) * len(shape))
-    order = sorted(range(len(shape)), key=lambda i: -shape[i])
-    for i in order:
-        if shape[i] % mesh_total == 0:
-            return P(*(("data", "model") if j == i else None
-                       for j in range(len(shape))))
-    return P(*(None,) * len(shape))
-
-
-def _strip_data(spec: P) -> P:
-    """ZeRO-1 param storage: drop the FSDP ("data") component — params are
-    TP-sharded only and live gathered; optimizer state keeps the data shard
-    and the post-update all-gather happens ONCE per step (out_shardings)."""
-    out = []
-    for e in spec:
-        if e == "data":
-            out.append(None)
-        elif isinstance(e, tuple):
-            kept = tuple(a for a in e if a != "data")
-            out.append(kept if kept else None)
-        else:
-            out.append(e)
-    return P(*out)
-
-
-def param_specs(cfg: ModelConfig, params_tree, mode: str | None = None) -> dict:
+def param_specs(cfg: ModelConfig, params_tree) -> dict:
     """Spec tree matching the param tree (stacked layers get leading None)."""
-    from repro.models.common import get_param_mode
-    mode = mode or get_param_mode()
 
     def rule(path, leaf):
         p = _path_str(path)
         ndim = len(leaf.shape)
-        if mode == "fsdp":
-            if p.startswith("layers/"):
-                return P(None, *_param_rule_fsdp(leaf.shape[1:], 256))
-            return _param_rule_fsdp(leaf.shape, 256)
         if p.startswith("layers/"):
-            spec = _param_rule(p, ndim - 1, cfg)
-            spec = P(None, *spec)
-        else:
-            spec = _param_rule(p, ndim, cfg)
-        if mode == "zero1":
-            spec = _strip_data(spec)
-        return spec
+            return P(None, *_param_rule(p, ndim - 1, cfg))
+        return _param_rule(p, ndim, cfg)
 
     return jax.tree_util.tree_map_with_path(rule, params_tree)
-
-
-def opt_specs(cfg: ModelConfig, params_tree, mode: str | None = None) -> dict:
-    """Optimizer-state spec per param: under zero1 this re-adds a "data"
-    shard on the first large dim the param spec leaves unsharded."""
-    from repro.models.common import get_param_mode
-    mode = mode or get_param_mode()
-    pspecs = param_specs(cfg, params_tree, mode)
-    if mode != "zero1":
-        return pspecs
-
-    def add_data(path, leaf):
-        spec = pspecs_flat[_path_str(path)]
-        shape = leaf.shape
-        used = set()
-        for e in spec:
-            if isinstance(e, tuple):
-                used.update(e)
-            elif e:
-                used.add(e)
-        if "data" in used:
-            return spec
-        entries = list(spec) + [None] * (len(shape) - len(spec))
-        for i, e in enumerate(entries):
-            if e is None and shape[i] % 16 == 0 and shape[i] >= 16:
-                entries[i] = "data"
-                return P(*entries)
-        return spec
-
-    pspecs_flat = {}
-    def record(path, spec):
-        pspecs_flat[_path_str(path)] = spec
-        return spec
-    jax.tree_util.tree_map_with_path(record, pspecs,
-                                     is_leaf=lambda x: isinstance(x, P))
-    return jax.tree_util.tree_map_with_path(add_data, params_tree)
 
 
 def param_shardings(cfg: ModelConfig, params_tree, mesh) -> dict:
@@ -192,9 +116,6 @@ def param_shardings(cfg: ModelConfig, params_tree, mesh) -> dict:
 # ---------------------------------------------------------------------------
 
 def _dp(mesh) -> tuple[str, ...] | str:
-    from repro.models.common import get_sharding_mode
-    if get_sharding_mode() == "fsdp":
-        return tuple(a for a in ("pod", "data", "model") if a in mesh.axis_names)
     return ("pod", "data") if "pod" in mesh.axis_names else "data"
 
 

@@ -31,7 +31,7 @@ from repro.optim import (
     init_state,
     warmup_cosine,
 )
-from repro.launch.sharding import batch_specs, cache_specs, opt_specs, param_specs
+from repro.launch.sharding import batch_specs, cache_specs, param_specs
 
 
 # ---------------------------------------------------------------------------
@@ -116,16 +116,9 @@ def make_shardings(arch: ArchConfig, shape: ShapeConfig, mesh,
             if backend_supports_memory_kinds():
                 opt_kind = "pinned_host"
 
-        def opt_leaf_spec(path, leaf):
-            # moments/master mirror the param spec; scalars replicate
-            if len(leaf.shape) == 0:
-                return NamedSharding(mesh, P(), memory_kind=opt_kind)
-            # find matching param spec by stripping the leaf name
-            return None  # placeholder, resolved below
-
         abs_opt = abstract_opt_state(arch, plan)
-        ospecs = opt_specs(cfg, params)
-        # build: leaves dict mirrors params tree with dict-of-arrays leaves
+
+        # moments/master mirror the param spec; scalars replicate
         def mirror(spec, leaf_dict):
             out = {}
             for k, v in leaf_dict.items():
@@ -140,7 +133,7 @@ def make_shardings(arch: ArchConfig, shape: ShapeConfig, mesh,
             return out
 
         leaves_sh = jax.tree.map(
-            mirror, ospecs, abs_opt["leaves"],
+            mirror, pspecs, abs_opt["leaves"],
             is_leaf=lambda x: isinstance(x, P) or (
                 isinstance(x, dict) and "master" in x
             ),
@@ -174,30 +167,6 @@ def build_train_step(arch: ArchConfig, shape: ShapeConfig, mesh,
     micro = max(1, min(arch.train.microbatches, shape.global_batch))
     opt_on_host = plan is not None and plan.opt_space is MemorySpace.HOST
 
-    # ZeRO-1: gradients reduce-scatter into the optimizer's (data-added)
-    # sharding at each microbatch boundary — without this the fp32 grad
-    # accumulator replicates across the data axis (params are TP-only).
-    from repro.models.common import get_param_mode, shard_hint
-    grad_constraint = None
-    if get_param_mode() == "zero1":
-        from repro.launch.sharding import opt_specs
-        ospecs = opt_specs(cfg, abstract_params(arch))
-
-        def grad_constraint(grads):
-            return jax.tree.map(
-                lambda g, s: jax.lax.with_sharding_constraint(g, s),
-                grads, ospecs)
-    elif get_param_mode() == "fsdp":
-        # keep grads in the (data x model)-sharded param layout — GSPMD will
-        # otherwise happily materialize the full fp32 embedding/lm_head grads
-        from repro.launch.sharding import param_specs
-        pspecs_g = param_specs(cfg, abstract_params(arch))
-
-        def grad_constraint(grads):
-            return jax.tree.map(
-                lambda g, s: jax.lax.with_sharding_constraint(g, s),
-                grads, pspecs_g)
-
     def loss(p, mb):
         return tf.loss_fn(p, mb, cfg, remat=remat, unroll=unroll)
 
@@ -207,8 +176,6 @@ def build_train_step(arch: ArchConfig, shape: ShapeConfig, mesh,
                            total_steps=total_steps)
         if micro == 1:
             l, grads = jax.value_and_grad(loss)(params, batch)
-            if grad_constraint is not None:
-                grads = grad_constraint(grads)
         else:
             mb_batch = jax.tree.map(
                 lambda x: x.reshape((micro, x.shape[0] // micro) + x.shape[1:]),
@@ -217,14 +184,10 @@ def build_train_step(arch: ArchConfig, shape: ShapeConfig, mesh,
             zeros = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params
             )
-            if grad_constraint is not None:
-                zeros = grad_constraint(zeros)
 
             def acc(carry, mb):
                 g_acc, l_acc = carry
                 l, g = jax.value_and_grad(loss)(params, mb)
-                if grad_constraint is not None:
-                    g = grad_constraint(g)
                 g_acc = jax.tree.map(
                     lambda a, b: a + b.astype(jnp.float32), g_acc, g
                 )

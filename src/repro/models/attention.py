@@ -14,7 +14,6 @@ story for 32k/500k decode and the solution to GQA kv_heads < model-axis size
 """
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
@@ -51,9 +50,6 @@ def _attention_dense(q, k, v, *, causal, window, q_offset, mask, scale):
     return o.reshape(b, sq, hq, dh)
 
 
-FSDP_Q_CHUNK = 512  # query rows per block under pure-FSDP (seq unsharded)
-
-
 def attention(q, k, v, *, causal: bool = True, window: int | None = None,
               q_offset=0, mask=None, softmax_scale: float | None = None):
     """q: (B,Sq,Hq,Dh), k/v: (B,Skv,Hkv,Dh) -> (B,Sq,Hq,Dh). fp32 softmax.
@@ -61,51 +57,27 @@ def attention(q, k, v, *, causal: bool = True, window: int | None = None,
     Dense (materialized-score) path — used for training where sequence
     parallelism bounds the per-device score block and the VJP is efficient
     under remat.  Long-sequence forward-only paths use attention_flash.
-    Under pure-FSDP (seq unsharded) queries are processed in causal-pruned
-    blocks so the fp32 score transient stays bounded.
     """
-    from repro.models.common import get_sharding_mode
     dh = q.shape[-1]
-    sq = q.shape[1]
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(dh)
-    if (get_sharding_mode() == "fsdp" and mask is None
-            and sq > FSDP_Q_CHUNK and sq % FSDP_Q_CHUNK == 0):
-        outs = []
-        for i in range(sq // FSDP_Q_CHUNK):
-            q_start = q_offset + i * FSDP_Q_CHUNK
-            qc = jax.lax.slice_in_dim(q, i * FSDP_Q_CHUNK,
-                                      (i + 1) * FSDP_Q_CHUNK, axis=1)
-            hi = k.shape[1]
-            lo = 0
-            if causal:
-                hi = min(hi, q_start + FSDP_Q_CHUNK)
-            if window is not None:
-                lo = max(0, q_start - window + 1)
-            kc = jax.lax.slice_in_dim(k, lo, hi, axis=1)
-            vc = jax.lax.slice_in_dim(v, lo, hi, axis=1)
-            outs.append(_attention_dense(
-                qc, kc, vc, causal=causal, window=window,
-                q_offset=q_start - lo, mask=None, scale=scale))
-        return jnp.concatenate(outs, axis=1)
     return _attention_dense(q, k, v, causal=causal, window=window,
                             q_offset=q_offset, mask=mask, scale=scale)
 
 
-# toggled by the dry-run cost probes: a scanned KV-block loop is counted
-# once by XLA cost analysis, so probes unroll it (and then out-of-band
-# blocks are skipped statically, matching the Pallas kernel's pl.when)
-UNROLL_FLASH = False
 FLASH_BLOCK = 1024
 
 
 def attention_flash(q, k, v, *, causal: bool = True, window: int | None = None,
                     q_offset=0, softmax_scale: float | None = None,
-                    block: int = FLASH_BLOCK):
+                    block: int = FLASH_BLOCK, unroll: bool = False):
     """Memory-bounded online-softmax attention (forward only — prefill/serve
     path; training uses the dense path whose VJP is efficient under remat).
 
     Streams KV in blocks with running (max, sum, acc) — the XLA-level
     rendering of kernels/flash_attention; identical math, grouped GQA.
+    ``unroll=True`` loops over the KV blocks in Python and skips out-of-band
+    blocks statically, matching the Pallas kernel's pl.when (the dry-run
+    cost probes: XLA cost analysis counts a scan body once).
     """
     b, sq, hq, dh = q.shape
     hkv = k.shape[2]
@@ -142,7 +114,7 @@ def attention_flash(q, k, v, *, causal: bool = True, window: int | None = None,
     m0 = jnp.full((b, hkv, g, sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, hkv, g, sq), jnp.float32)
     a0 = jnp.zeros((b, sq, hkv, g, dh), jnp.float32)
-    if UNROLL_FLASH:
+    if unroll:
         carry = (m0, l0, a0)
         for j in range(nb):
             lo, hi = j * block, (j + 1) * block

@@ -12,41 +12,13 @@ UNC = P.UNCONSTRAINED
 
 
 BATCH = "__batch__"  # sentinel: replaced by the DP axes of the context mesh
-SEQ = "__seq__"      # sentinel: "model" under 2D (TP+SP) sharding, unsharded
-                     # under pure-FSDP ("model" joins the batch axes instead)
-
-# Sharding mode: "2d" = TP over model + SP residual stream + FSDP over data
-# (the baseline); "fsdp" = pure parameter sharding over (data x model) with
-# batch over all axes — the §Perf beyond-paper variant (per-layer param
-# all-gather once per pass, no SP<->TP activation reshards).
-_SHARDING_MODE = "2d"
-
-
-def set_sharding_mode(mode: str) -> None:
-    """"2d" (TP+SP+FSDP), "fsdp" (pure), "zero1" (TP params + data-sharded
-    optimizer state; activation hints behave like 2d)."""
-    global _SHARDING_MODE
-    assert mode in ("2d", "fsdp", "zero1"), mode
-    _SHARDING_MODE = "2d" if mode == "zero1" else mode
-    global _PARAM_MODE
-    _PARAM_MODE = mode
-
-
-_PARAM_MODE = "2d"
-
-
-def get_param_mode() -> str:
-    return _PARAM_MODE
-
-
-def get_sharding_mode() -> str:
-    return _SHARDING_MODE
+SEQ = "__seq__"      # sentinel: "model" (sequence parallelism on the
+                     # residual stream; TP over model, FSDP over data)
 
 
 def batch_axes_from_ctx() -> tuple[str, ...]:
     names = set(jax.sharding.get_abstract_mesh().axis_names)
-    axes = ("pod", "data", "model") if _SHARDING_MODE == "fsdp" else ("pod", "data")
-    return tuple(a for a in axes if a in names)
+    return tuple(a for a in ("pod", "data") if a in names)
 
 
 def shard_hint(x, spec: P):
@@ -67,7 +39,7 @@ def shard_hint(x, spec: P):
             resolved.append(dp if dp else None)
             continue
         if e == SEQ:
-            resolved.append("model" if _SHARDING_MODE == "2d" else None)
+            resolved.append("model")
             continue
         resolved.append(e)
     needed = set()

@@ -73,7 +73,7 @@ def _routing(x_flat, router_w, top_k: int, capacity: int, num_experts: int):
 def moe(params, x, *, top_k: int, activation: str,
         capacity_factor: float = CAPACITY_FACTOR, group_size: int | None = None):
     """x: (B,S,d) -> (B,S,d), plus aux loss (returned via tuple)."""
-    group_size = group_size or MOE_GROUP_SIZE  # read the global at call time
+    group_size = group_size or MOE_GROUP_SIZE
     b, s, d = x.shape
     e = params["w_up"].shape[0]
     tokens = b * s
@@ -97,9 +97,7 @@ def moe(params, x, *, top_k: int, activation: str,
     else:
         act = jax.nn.gelu if activation == "gelu" else lambda z: jax.nn.relu(z) ** 2
         h = act(jnp.einsum("gecd,edf->gecf", expert_in, params["w_up"]))
-    from repro.models.common import get_sharding_mode
-    h = shard_hint(h, P(BATCH, None, UNC,
-                        "model" if get_sharding_mode() == "2d" else None))
+    h = shard_hint(h, P(BATCH, None, UNC, "model"))
     expert_out = jnp.einsum("gecf,efd->gecd", h, params["w_down"])
     expert_out = shard_hint(expert_out, P(BATCH, None, UNC, UNC))
     out = jnp.einsum("gecd,gsec->gsd", expert_out, combine.astype(x.dtype))

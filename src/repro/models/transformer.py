@@ -157,7 +157,7 @@ def _position_embed(q, k, positions, cfg: ModelConfig):
 
 
 def attn_sublayer(p, x, cfg: ModelConfig, positions, *, return_kv=False,
-                  mode: str = "train"):
+                  mode: str = "train", unroll: bool = False):
     B, S, _ = x.shape
     q, k, v = _project_qkv(p, x, cfg)
     q, k = _position_embed(q, k, positions, cfg)
@@ -168,7 +168,7 @@ def attn_sublayer(p, x, cfg: ModelConfig, positions, *, return_kv=False,
     v = shard_hint(v, P(BATCH, None, UNC, UNC))
     if mode == "prefill" and S * k.shape[1] > 4096 * 4096:
         out = attn_lib.attention_flash(q, k, v, causal=True,
-                                       window=cfg.sliding_window)
+                                       window=cfg.sliding_window, unroll=unroll)
     else:
         out = attn_lib.attention(q, k, v, causal=True, window=cfg.sliding_window)
     out = out.reshape(B, S, -1) @ p["wo"]
@@ -178,12 +178,12 @@ def attn_sublayer(p, x, cfg: ModelConfig, positions, *, return_kv=False,
 
 
 def transformer_block(p, x, cfg: ModelConfig, positions, *, return_kv=False,
-                      mode: str = "train"):
+                      mode: str = "train", unroll: bool = False):
     h = apply_norm(x, p["ln1"], cfg.norm)
     aux = jnp.zeros((), jnp.float32)
     if cfg.family == "hybrid":
         res = attn_sublayer(p["attn"], h, cfg, positions, return_kv=return_kv,
-                            mode=mode)
+                            mode=mode, unroll=unroll)
         a_out, kv = res if return_kv else (res, None)
         m_out, _ = ssm_lib.mamba(p["mamba"], h)
         y = 0.5 * (
@@ -192,7 +192,7 @@ def transformer_block(p, x, cfg: ModelConfig, positions, *, return_kv=False,
         )
     else:
         res = attn_sublayer(p["attn"], h, cfg, positions, return_kv=return_kv,
-                            mode=mode)
+                            mode=mode, unroll=unroll)
         y, kv = res if return_kv else (res, None)
     x = x + y
     h = apply_norm(x, p["ln2"], cfg.norm)
@@ -274,66 +274,19 @@ def logits_fn(params, x, cfg: ModelConfig):
         pad_mask = jnp.arange(cfg.padded_vocab) >= cfg.vocab_size
         logits = jnp.where(pad_mask, jnp.float32(-1e30).astype(logits.dtype), logits)
     # vocab-parallel logits: keep V sharded over model through the loss
-    # (under pure-FSDP the model axis belongs to the batch — V unsharded)
-    from repro.models.common import get_sharding_mode
-    vshard = "model" if get_sharding_mode() == "2d" else None
     if logits.ndim == 4:  # (B,S,K,V) multi-codebook
-        return shard_hint(logits, P(BATCH, UNC, None, vshard))
-    return shard_hint(logits, P(BATCH, UNC, vshard))
-
-
-CE_CHUNK = 512  # seq positions per chunked-CE block (pure-FSDP path)
-
-
-def _chunked_ce(params, x, labels, cfg: ModelConfig, unroll: bool):
-    """Sequence-chunked vocab loss: never materializes the full (B,S,V)
-    fp32 logits — each chunk's logits are recomputed in the backward pass
-    (jax.checkpoint).  Used under pure-FSDP where the seq dim is unsharded
-    (under 2D/SP sharding the full logits are already 1/16-sharded)."""
-    B, S, _ = x.shape
-    nc = S // CE_CHUNK
-
-    @jax.checkpoint
-    def chunk_nll(xc, lc):
-        logits = logits_fn(params, xc, cfg)
-        logits = logits.astype(jnp.float32)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        onehot = lc[..., None] == jnp.arange(logits.shape[-1], dtype=lc.dtype)
-        tgt = jnp.sum(jnp.where(onehot, logits, 0.0), axis=-1)
-        mask = (lc != -1).astype(jnp.float32)
-        return jnp.sum((lse - tgt) * mask), jnp.sum(mask)
-
-    xs = (x.reshape(B, nc, CE_CHUNK, -1).transpose(1, 0, 2, 3),
-          labels.reshape(B, nc, CE_CHUNK).transpose(1, 0, 2))
-    if unroll:
-        tot = cnt = 0.0
-        for i in range(nc):
-            t, c = chunk_nll(xs[0][i], xs[1][i])
-            tot, cnt = tot + t, cnt + c
-    else:
-        def body(carry, args):
-            t, c = chunk_nll(*args)
-            return (carry[0] + t, carry[1] + c), None
-
-        (tot, cnt), _ = jax.lax.scan(body, (0.0, 0.0), xs)
-    return tot / jnp.maximum(cnt, 1.0)
+        return shard_hint(logits, P(BATCH, UNC, None, "model"))
+    return shard_hint(logits, P(BATCH, UNC, "model"))
 
 
 def loss_fn(params, batch, cfg: ModelConfig, *, remat: str = "full",
             unroll: bool = False):
     """Next-token loss. batch: tokens (B,S) [or (B,S,K) audio; embeds for
     vlm/audio stubs] + labels; aux MoE loss folded in."""
-    from repro.models.common import get_sharding_mode
     x, positions = embed_inputs(params, batch, cfg)
     x, aux = backbone(params, x, cfg, positions, remat=remat, unroll=unroll)
-    labels = batch["labels"]
-    S = x.shape[1]
-    if (get_sharding_mode() == "fsdp" and labels.ndim == 2
-            and S % CE_CHUNK == 0 and S > CE_CHUNK):
-        loss = _chunked_ce(params, x, labels, cfg, unroll)
-    else:
-        logits = logits_fn(params, x, cfg)
-        loss = cross_entropy_loss(logits, labels)
+    logits = logits_fn(params, x, cfg)
+    loss = cross_entropy_loss(logits, batch["labels"])
     if cfg.num_experts:
         loss = loss + 0.01 * aux / cfg.num_layers
     return loss
@@ -499,7 +452,8 @@ def decode_step(params, batch, caches, cache_len, cfg: ModelConfig,
 
 
 def prefill(params, batch, cfg: ModelConfig, unroll: bool = False):
-    """Full-sequence forward returning last-position logits + filled caches."""
+    """Full-sequence forward returning last-position logits + filled caches.
+    ``unroll`` loops over the layers and the flash KV blocks in Python."""
     x, positions = embed_inputs(params, batch, cfg)
     B, S = x.shape[0], x.shape[1]
 
@@ -520,7 +474,8 @@ def prefill(params, batch, cfg: ModelConfig, unroll: bool = False):
             if cfg.family == "hybrid":
                 hn = apply_norm(h, lw["ln1"], cfg.norm)
                 a_out, kv = attn_sublayer(lw["attn"], hn, cfg, positions,
-                                          return_kv=True, mode="prefill")
+                                          return_kv=True, mode="prefill",
+                                          unroll=unroll)
                 m_out, mstate = ssm_lib.mamba(lw["mamba"], hn)
                 y = 0.5 * (
                     apply_norm(a_out, lw["attn_out_norm"], "rmsnorm")
@@ -537,7 +492,8 @@ def prefill(params, batch, cfg: ModelConfig, unroll: bool = False):
                 }
                 return (h, aux), cache
             h, aux2, kv = transformer_block(lw, h, cfg, positions,
-                                            return_kv=True, mode="prefill")
+                                            return_kv=True, mode="prefill",
+                                            unroll=unroll)
             h = shard_hint(h, hint)
             k, v = kv
             return (h, aux + aux2), {"k": k[:, -S_cache:], "v": v[:, -S_cache:]}

@@ -11,6 +11,8 @@ Usage in a test module:
     except ImportError:
         from _hypothesis_fallback import given, settings, st
 """
+import inspect
+
 import pytest
 
 
@@ -35,9 +37,14 @@ def settings(*args, **kwargs):
 def given(*args, **kwargs):
     def decorate(fn):
         @pytest.mark.skip(reason="hypothesis not installed")
-        def placeholder():
+        def placeholder(*a, **k):
             pass
         placeholder.__name__ = fn.__name__
         placeholder.__doc__ = fn.__doc__
+        # keep the arguments no strategy draws (positional strategies draw
+        # the last ones), so that pytest.mark.parametrize can still name them
+        params = list(inspect.signature(fn).parameters.values())
+        params = [q for q in params[:len(params) - len(args)] if q.name not in kwargs]
+        placeholder.__signature__ = inspect.Signature(params)
         return placeholder
     return decorate

@@ -122,9 +122,10 @@ def test_decode_step_never_repeats_kv(arch_name):
     assert f"tensor<{B}x{S}x8x{Dh}x" not in text
 
 
+@pytest.mark.parametrize("unroll", [False, True])
 @settings(max_examples=15, deadline=None)
 @given(seed=st.integers(0, 2**31 - 1), window=st.sampled_from([None, 16, 48]))
-def test_flash_equals_dense_property(seed, window):
+def test_flash_equals_dense_property(unroll, seed, window):
     key = jax.random.key(seed)
     B, S, Hq, Hkv, Dh = 1, 96, 2, 1, 8
     ks = jax.random.split(key, 3)
@@ -132,7 +133,8 @@ def test_flash_equals_dense_property(seed, window):
     k = jax.random.normal(ks[1], (B, S, Hkv, Dh))
     v = jax.random.normal(ks[2], (B, S, Hkv, Dh))
     dense = attention(q, k, v, causal=True, window=window)
-    flash = attention_flash(q, k, v, causal=True, window=window, block=32)
+    flash = attention_flash(q, k, v, causal=True, window=window, block=32,
+                            unroll=unroll)
     np.testing.assert_allclose(np.asarray(flash), np.asarray(dense), atol=1e-5)
 
 

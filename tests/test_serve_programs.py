@@ -1,7 +1,8 @@
 """The serving entry's table of compiled step programs: a call whose key
-(model config, batch, prompt length, cache length, mesh, the package's module
-globals) was served before lowers and compiles nothing, and gives what a first
-call gives; each part of the key misses on its own."""
+(model config, batch, prompt length, cache length, mesh, and the globals of
+the package's modules, so that a tuning constant or a function rebound
+in-process misses) was served before lowers and compiles nothing, and gives
+what a first call gives; each part of the key misses on its own."""
 import dataclasses
 import gc
 import json
@@ -17,7 +18,6 @@ import pytest
 from repro.launch import serve as serve_mod
 from repro.models import moe
 from repro.models import transformer as tf
-from repro.models.common import get_sharding_mode, set_sharding_mode
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 BUILD = {"serve.lower.prefill", "serve.compile.prefill",
@@ -85,19 +85,16 @@ def _with_other_theta(serving_arch):
 
 
 @pytest.mark.parametrize("part", ["batch", "prompt_len", "gen", "layers",
-                                  "model_config", "sharding_mode", "module_constant",
-                                  "module_function"])
+                                  "model_config", "module_constant", "module_function"])
 def test_each_key_part_misses_on_its_own(part):
     assert _built(_serve())
     kw = {"batch": {"batch": 3}, "prompt_len": {"prompt_len": 9}, "gen": {"gen": 5},
           "layers": {"layers": 1}}.get(part, {})
-    arch, mode = serve_mod.serving_arch, get_sharding_mode()
+    arch = serve_mod.serving_arch
     group, mlp = moe.MOE_GROUP_SIZE, tf.mlp
     try:
         if part == "model_config":
             serve_mod.serving_arch = _with_other_theta(arch)
-        if part == "sharding_mode":
-            set_sharding_mode("zero1")   # another param mode
         if part == "module_constant":
             moe.MOE_GROUP_SIZE = group * 2
         if part == "module_function":
@@ -106,9 +103,7 @@ def test_each_key_part_misses_on_its_own(part):
         assert not _built(_serve(**kw)), part
     finally:
         serve_mod.serving_arch = arch
-        set_sharding_mode(mode)
         moe.MOE_GROUP_SIZE, tf.mlp = group, mlp
-    assert get_sharding_mode() == mode
     # the first key is still kept, and still hits
     assert not _built(_serve())
 
