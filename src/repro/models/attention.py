@@ -1,9 +1,13 @@
 """Attention: GQA with causal / sliding-window masks; prefill and decode.
 
-Reference (pure-jnp) paths here; the Pallas flash/paged kernels in
-repro.kernels are drop-in replacements selected by ``use_pallas`` (the
-dry-run lowers the reference path — GSPMD shards it — while kernel tests
-validate the Pallas implementations against these functions).
+The XLA (pure-jnp) paths live here. Which one a layer runs is decided by
+the placement of its input (``transformer.attn_sublayer``): a prefill whose
+sequences each lie whole on one device (no mesh, or a ``model`` axis of
+size 1) runs in the Pallas flash kernel (``repro.kernels.flash_attention``);
+a prefill whose mesh splits the sequence over ``model`` runs ``attention``,
+or ``attention_flash`` above 4096² query-key pairs, which GSPMD shards;
+training runs ``attention``. The kernel tests check the Pallas kernels
+against these functions.
 
 Decode uses *split-KV* (flash-decoding style): when the KV cache is sharded
 over the ``model`` mesh axis along the sequence dimension, each shard
@@ -54,9 +58,10 @@ def attention(q, k, v, *, causal: bool = True, window: int | None = None,
               q_offset=0, mask=None, softmax_scale: float | None = None):
     """q: (B,Sq,Hq,Dh), k/v: (B,Skv,Hkv,Dh) -> (B,Sq,Hq,Dh). fp32 softmax.
 
-    Dense (materialized-score) path — used for training where sequence
-    parallelism bounds the per-device score block and the VJP is efficient
-    under remat.  Long-sequence forward-only paths use attention_flash.
+    Dense (materialized-score) path: training, where sequence parallelism
+    bounds the per-device score block and the VJP is efficient under remat,
+    and a sequence-split prefill up to 4096² query-key pairs (above it,
+    attention_flash). A prefill on one device runs the Pallas flash kernel.
     """
     dh = q.shape[-1]
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(dh)
@@ -70,11 +75,12 @@ FLASH_BLOCK = 1024
 def attention_flash(q, k, v, *, causal: bool = True, window: int | None = None,
                     q_offset=0, softmax_scale: float | None = None,
                     block: int = FLASH_BLOCK, unroll: bool = False):
-    """Memory-bounded online-softmax attention (forward only — prefill/serve
-    path; training uses the dense path whose VJP is efficient under remat).
+    """Memory-bounded online-softmax attention (forward only: a prefill
+    whose mesh splits the sequence, above 4096² query-key pairs; training
+    uses the dense path whose VJP is efficient under remat).
 
     Streams KV in blocks with running (max, sum, acc) — the XLA-level
-    rendering of kernels/flash_attention; identical math, grouped GQA.
+    rendering of kernels/flash_attention, grouped GQA; it scores in f32.
     ``unroll=True`` loops over the KV blocks in Python and skips out-of-band
     blocks statically, matching the Pallas kernel's pl.when (the dry-run
     cost probes: XLA cost analysis counts a scan body once).

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.streaming import checkpoint_layer
+from repro.kernels.flash_attention.ops import flash_attention
 from repro.models import attention as attn_lib
 from repro.models import moe as moe_lib
 from repro.models import rwkv as rwkv_lib
@@ -156,6 +157,13 @@ def _position_embed(q, k, positions, cfg: ModelConfig):
     return q, k
 
 
+def _splits_sequence() -> bool:
+    """Whether the context mesh shards the sequence: SEQ resolves to the
+    ``model`` axis, so a mesh without one, or with one of size 1, holds each
+    sequence whole on a device."""
+    return jax.sharding.get_abstract_mesh().shape.get("model", 1) > 1
+
+
 def attn_sublayer(p, x, cfg: ModelConfig, positions, *, return_kv=False,
                   mode: str = "train", unroll: bool = False):
     B, S, _ = x.shape
@@ -166,7 +174,11 @@ def attn_sublayer(p, x, cfg: ModelConfig, positions, *, return_kv=False,
     q = shard_hint(q, P(BATCH, SEQ, UNC, UNC))
     k = shard_hint(k, P(BATCH, None, UNC, UNC))
     v = shard_hint(v, P(BATCH, None, UNC, UNC))
-    if mode == "prefill" and S * k.shape[1] > 4096 * 4096:
+    # a sequence whole on one device: the Pallas kernel keeps the scores in
+    # VMEM; a sequence split over the mesh: XLA paths that GSPMD shards
+    if mode == "prefill" and not _splits_sequence():
+        out = flash_attention(q, k, v, causal=True, window=cfg.sliding_window)
+    elif mode == "prefill" and S * k.shape[1] > 4096 * 4096:
         out = attn_lib.attention_flash(q, k, v, causal=True,
                                        window=cfg.sliding_window, unroll=unroll)
     else:

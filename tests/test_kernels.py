@@ -81,7 +81,7 @@ def test_matmul_sweep(m, k, n, dtype, key):
 # Flash attention
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2), (8, 1)])
+@pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2), (8, 1), (24, 2)])
 @pytest.mark.parametrize("window", [None, 64])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_sweep(hq, hkv, window, dtype, key):
@@ -107,6 +107,51 @@ def test_flash_attention_cross_lengths(key):
     out = flash_attention(q, k, v, block_q=128, block_kv=128)
     ref = flash_attention_ref(q, k, v)
     np.testing.assert_allclose(out, ref, atol=2e-3)
+
+
+def _f32_ref(q, k, v, **kw):
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    return np.asarray(flash_attention_ref(*f32, **kw))
+
+
+@pytest.mark.parametrize("sq,skv", [(500, 500), (200, 500)])
+@pytest.mark.parametrize("causal,window", [(True, None), (True, 64), (False, None)])
+def test_flash_attention_pads_lengths_that_are_no_block_multiple(
+        sq, skv, causal, window, key):
+    """500 = 3 * 128 + 116: the wrapper pads queries and keys to the block
+    and slices the padded rows off; padded keys never get weight."""
+    B, Hq, Hkv, Dh = 2, 4, 2, 32
+    ks = jax.random.split(key, 3)
+    q = jax.random.normal(ks[0], (B, sq, Hq, Dh), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (B, skv, Hkv, Dh), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (B, skv, Hkv, Dh), jnp.bfloat16)
+    out = flash_attention(q, k, v, causal=causal, window=window,
+                          block_q=128, block_kv=128)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               _f32_ref(q, k, v, causal=causal, window=window),
+                               atol=3e-2)
+    # with every value 1 the weights show: a padded key (value 0) would
+    # take weight from the real ones and pull the output below 1
+    ones = flash_attention(q, k, jnp.ones_like(v), causal=causal,
+                           window=window, block_q=128, block_kv=128)
+    np.testing.assert_allclose(np.asarray(ones, np.float32), 1.0, atol=5e-3)
+
+
+@pytest.mark.parametrize("window", [None, 200])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_at_starcoder2_3b_heads(window, dtype, key):
+    """Hq 24 over Hkv 2 (G 12 query heads a grid step), Dh 128, at a
+    reduced length of three blocks and a part."""
+    B, S, Hq, Hkv, Dh = 1, 400, 24, 2, 128
+    ks = jax.random.split(key, 3)
+    q = jax.random.normal(ks[0], (B, S, Hq, Dh), dtype)
+    k = jax.random.normal(ks[1], (B, S, Hkv, Dh), dtype)
+    v = jax.random.normal(ks[2], (B, S, Hkv, Dh), dtype)
+    out = flash_attention(q, k, v, window=window, block_q=128, block_kv=128)
+    atol = 2e-3 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               _f32_ref(q, k, v, window=window), atol=atol)
 
 
 # ---------------------------------------------------------------------------
